@@ -5,6 +5,8 @@ transactional graph serving with the one-hop sub-query result cache.
 of vertices and edges"), vertex-partitioned over the full mesh with the
 cache co-partitioned; peak 8k concurrent one-hop gR-Txs per step."""
 
+import dataclasses
+
 from repro.distributed.graph_serve import GraphServeConfig
 
 FAMILY = "graph"
@@ -16,6 +18,20 @@ FULL = GraphServeConfig(
     max_deg=64,
     max_leaves=64,
     cache_slots_total=2**26,
+)
+
+# One TPU v5e chip's share of FULL (16 GB of HBM). Only the scale is cut:
+# widths, degree, template and cache value width are FULL's. The store plus
+# cache take ~5.6 GB of the chip, so a gRW commit's input and output
+# stores (the commit step does not donate them) fit together.
+CHIP = dataclasses.replace(
+    FULL, name="ecommerce-graph-chip", v_total=2**23, cache_slots_total=2**22,
+)
+CHIP_REDUCED = (
+    "v_total 2^30 -> 2^23 per chip (67M edges): a commit's input + output "
+    "store must fit one v5e's 16 GB",
+    "cache_slots_total 2^26 -> 2^22 per chip (1.2 GB): one slot per 2 "
+    "vertices, where FULL has one per 16",
 )
 
 SMOKE = GraphServeConfig(

@@ -126,7 +126,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.cache import CacheState, empty_cache
@@ -178,7 +178,7 @@ from repro.graphstore.partition import (
     apply_mutations_partitioned,
     default_pspec,
     owner_of,
-    partition_store,
+    partition_store_host,
     store_bytes_report,
 )
 from repro.distributed.routing import (
@@ -592,24 +592,22 @@ class ShardedTxnRuntime:
 
     # ------------------------------------------------------------ sharding
     def cache_sharding(self):
-        # vals (2D) deliberately shares s1 = P(ax), not P(ax, None): the
-        # trailing None is the same placement but shard_map outputs drop
-        # it, and a spelling mismatch is a fresh executable-cache entry
-        # (see the _ax note in __init__) — a device_put under the other
-        # spelling would recompile the serve step on the first post-drain
-        # batch (pinned by the zero-recompile test in test_routing_runtime)
-        s1 = NamedSharding(self.mesh, P(self._ax))
-        s2 = s1
-        s0 = NamedSharding(self.mesh, P())
-        return CacheState(
-            tpl=s1, root=s1, fp=s1, chunk=s1, total_len=s1, vals=s2,
-            version=s1, valid=s1,
-            n_hit=s0, n_miss=s0, n_insert=s0, n_evict=s0, n_delete=s0,
-            n_oversize=s0,
+        """NamedShardings laying the cache over the mesh — built from the
+        same specs the steps' shard_map outputs carry, so a device_put
+        cache and a step-returned cache are one executable-cache key."""
+        return jax.tree_util.tree_map(
+            lambda s: NamedSharding(self.mesh, s), self._cache_specs(),
+            is_leaf=lambda x: isinstance(x, P),
         )
 
     def _cache_specs(self):
-        a = self.axes
+        # one entry per array dimension (vals is 2D: P(ax, None)), the
+        # spelling a jitted step's outputs carry. P(ax) places vals the
+        # same, but the jit fastpath keys on the spelling: a device_put
+        # cache under one and a step-returned cache under the other would
+        # be a second executable of the serve step (see the _ax note in
+        # __init__; pinned by the zero-recompile tests)
+        a = self._ax
         return CacheState(
             tpl=P(a), root=P(a), fp=P(a), chunk=P(a), total_len=P(a),
             vals=P(a, None), version=P(a), valid=P(a),
@@ -621,14 +619,17 @@ class ShardedTxnRuntime:
         """shard_map PartitionSpecs for the storage tier."""
         if self.pspec is None:
             return P()  # replicated snapshot
+        # one entry per array dimension, like the cache specs: the
+        # spelling a step's returned store carries
         a = self._ax
         blk = EdgeBlock(
-            key=P(a), other=P(a), label=P(a), alive=P(a), props=P(a),
+            key=P(a), other=P(a), label=P(a), alive=P(a), props=P(a, None),
             geid=P(a), gperm=P(a), indptr=P(a), blk_len=P(a), csr_len=P(a),
         )
         return PartitionedGraphStore(
-            vlabel=P(), valive=P(), vprops=P(), vversion=P(),
-            out=blk, inc=blk, v_len=P(), e_len=P(), version=P(),
+            vlabel=P(None), valive=P(None), vprops=P(None, None),
+            vversion=P(None), out=blk, inc=blk, v_len=P(), e_len=P(),
+            version=P(),
         )
 
     def store_sharding(self):
@@ -640,7 +641,9 @@ class ShardedTxnRuntime:
 
     def partition_store(self, store, *, elastic: bool = False) -> PartitionedGraphStore:
         """Partition a full ``GraphStore`` into this runtime's owner-local
-        blocks and lay it over the mesh (partitioned tier only).
+        blocks and lay it over the mesh (partitioned tier only). The blocks
+        are built on the host and each shard's slice goes straight to its
+        device, so no device stages the whole store.
 
         With ``elastic=True`` an over-capacity orientation grows
         ``e_blk_cap`` (25% headroom over the reported need) and retries
@@ -650,7 +653,7 @@ class ShardedTxnRuntime:
         assert self.pspec is not None, "replicated tier keeps full snapshots"
         while True:
             try:
-                ps = partition_store(self.pspec, store)
+                ps = partition_store_host(self.pspec, store)
                 break
             except BlockCapacityError as e:
                 if not elastic:
@@ -718,7 +721,7 @@ class ShardedTxnRuntime:
             sm = shard_map(
                 local_compact, mesh=self.mesh,
                 in_specs=(self._store_specs(),),
-                out_specs=self._store_specs(), check_rep=False,
+                out_specs=self._store_specs(), check_vma=False,
             )
             self._maint_fns[key] = jax.jit(sm)
         return self._maint_fns[key]
@@ -742,7 +745,7 @@ class ShardedTxnRuntime:
             sm = shard_map(
                 local_grow, mesh=self.mesh,
                 in_specs=(self._store_specs(),),
-                out_specs=self._store_specs(), check_rep=False,
+                out_specs=self._store_specs(), check_vma=False,
             )
             self._grow_fns[key] = jax.jit(sm)
         return self._grow_fns[key]
@@ -1073,7 +1076,7 @@ class ShardedTxnRuntime:
                 P(self.axes), P(self.axes), P(self.axes), P(self.axes),
                 P(), P(),
             ),
-            check_rep=False,
+            check_vma=False,
         )
 
     def _gr(self, plan, bucket: int, *, pspec=None, worst_case: bool = False):
@@ -1361,7 +1364,7 @@ class ShardedTxnRuntime:
                     # on-device maintenance gate — ops were derived above,
                     # so the layout change cannot perturb this commit's
                     # invalidation; compact_block is collective-free, so a
-                    # per-shard lax.cond is legal under check_rep=False
+                    # per-shard lax.cond is legal under check_vma=False
                     def maybe_compact(blk):
                         rec = blk.blk_len[0] - blk.csr_len[0]
                         hit = rec >= thresh
@@ -1438,7 +1441,7 @@ class ShardedTxnRuntime:
                 self._store_specs(), self._cache_specs(), P(), P(), P(),
                 P(), P(), P(),
             ),
-            check_rep=False,
+            check_vma=False,
         )
 
     def _grw(self, policy: str, gate: DeviceGate | None = None, *,
@@ -1623,7 +1626,7 @@ class ShardedTxnRuntime:
                     self._cache_specs(), P(), P(), P(), P(), P(), P(),
                 ),
                 out_specs=(self._cache_specs(), P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
             self._pop_fns[key] = jax.jit(sm)
         return self._pop_fns[key]

@@ -42,13 +42,14 @@ global id assignment needs no coordination.
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.routing import storage_owner_of
+from repro.distributed.routing import base_owner, storage_owner_of
 from repro.graphstore.store import INT32_MAX, GraphStore, StoreSpec
 from repro.utils import PROP_MISSING, take_along0
 
@@ -172,8 +173,13 @@ class PartitionedGraphStore(NamedTuple):
 
 # ------------------------------------------------------------------ build
 def _build_block(pspec: PartitionedStoreSpec, keyside, otherside, elabel,
-                 ealive, eprops, e_len: int, csr_len: int):
-    """Host-side construction of one orientation's blocks (numpy)."""
+                 ealive, eprops, perm, e_len: int, csr_len: int):
+    """Host-side construction of one orientation's blocks (numpy).
+
+    ``perm`` is the source store's CSR permutation for this orientation:
+    ``perm[:csr_len]`` lists the CSR slots stably sorted by key, so each
+    shard's CSR region is that order filtered to the shard's keys — the
+    single-host lane order, with no second sort."""
     spec, n = pspec.base, pspec.n_shards
     EB, Vloc = pspec.e_blk_cap, pspec.v_loc
     nep = spec.n_eprops
@@ -188,8 +194,7 @@ def _build_block(pspec: PartitionedStoreSpec, keyside, otherside, elabel,
     blk_len = np.zeros((n,), np.int32)
     csr_blk = np.zeros((n,), np.int32)
 
-    slots = np.arange(e_len)
-    owner = np.mod(keyside[slots], n)
+    owner = base_owner(keyside[:e_len], n)
     counts = np.bincount(owner, minlength=n) if e_len else np.zeros(n, np.int64)
     if counts.max(initial=0) > EB:
         worst = int(counts.argmax())
@@ -200,16 +205,17 @@ def _build_block(pspec: PartitionedStoreSpec, keyside, otherside, elabel,
             f"elastic=True) to grow block capacity automatically.",
             needed=int(counts.max()),
         )
-    for s in range(n):
-        mine = slots[owner == s]
-        csr_mine = mine[mine < csr_len]
-        rec_mine = mine[mine >= csr_len]
-        # CSR region: stable sort by owner-side key; ties keep global-slot
-        # order, matching the single-host stable argsort lane order exactly
-        order = np.argsort(keyside[csr_mine], kind="stable")
-        csr_sorted = csr_mine[order]
+    csr_order = np.asarray(perm[:csr_len])
+    csr_owner = owner[csr_order]
+
+    def fill(s):
+        # CSR region in key order, ties in global-slot order (the stable
+        # sort's), then the recent region in slot order
+        csr_sorted = csr_order[csr_owner == s]
+        mine = owner == s
+        rec_mine = np.flatnonzero(mine[csr_len:]) + csr_len
         local = np.concatenate([csr_sorted, rec_mine])
-        m = len(local)
+        k, m = len(csr_sorted), len(local)
         base = s * EB
         key[base : base + m] = keyside[local]
         other[base : base + m] = otherside[local]
@@ -218,29 +224,34 @@ def _build_block(pspec: PartitionedStoreSpec, keyside, otherside, elabel,
         props[base : base + m] = eprops[local]
         geid[base : base + m] = local
         blk_len[s] = m
-        csr_blk[s] = len(csr_sorted)
+        csr_blk[s] = k
         # sorted geid->slot index: allocated slots by ascending geid, then
-        # the unallocated tail in slot order (stable ties on the sentinel)
-        masked = np.where(
-            np.arange(EB) < m, geid[base : base + EB].astype(np.int64),
-            np.int64(INT32_MAX),
-        )
-        gperm[base : base + EB] = np.argsort(masked, kind="stable")
+        # the unallocated tail in slot order. Recent geids exceed every CSR
+        # geid and ascend already; the CSR part inverts each slot's rank
+        # among this shard's CSR slots
+        rank = (np.cumsum(mine[:csr_len]) - 1)[csr_sorted]
+        gperm[base + rank] = np.arange(k, dtype=np.int32)
+        gperm[base + k : base + EB] = np.arange(k, EB, dtype=np.int32)
         lk = keyside[csr_sorted] // n  # interleaved: local index = v // n
         indptr[s * (Vloc + 1) : (s + 1) * (Vloc + 1)] = np.searchsorted(
             lk, np.arange(Vloc + 1), side="left"
         )
+
+    # shards write disjoint slices, and numpy's gathers release the GIL
+    with ThreadPoolExecutor(max_workers=n) as pool:
+        list(pool.map(fill, range(n)))
     return EdgeBlock(
-        key=jnp.asarray(key), other=jnp.asarray(other), label=jnp.asarray(label),
-        alive=jnp.asarray(alive), props=jnp.asarray(props),
-        geid=jnp.asarray(geid), gperm=jnp.asarray(gperm),
-        indptr=jnp.asarray(indptr),
-        blk_len=jnp.asarray(blk_len), csr_len=jnp.asarray(csr_blk),
+        key=key, other=other, label=label, alive=alive, props=props,
+        geid=geid, gperm=gperm, indptr=indptr, blk_len=blk_len,
+        csr_len=csr_blk,
     )
 
 
-def partition_store(pspec: PartitionedStoreSpec, store: GraphStore) -> PartitionedGraphStore:
-    """Partition a (host or device) ``GraphStore`` into owner-local blocks.
+def partition_store_host(pspec: PartitionedStoreSpec,
+                         store: GraphStore) -> PartitionedGraphStore:
+    """Partition a (host or device) ``GraphStore`` into owner-local blocks
+    held as numpy arrays — the form ``jax.device_put`` scatters over a
+    mesh shard by shard, so no device ever stages the whole store.
 
     Pure layout change: the partitioned store serves byte-identical reads.
     Dead-but-allocated edges keep their CSR lanes (they are masked at read
@@ -248,18 +259,27 @@ def partition_store(pspec: PartitionedStoreSpec, store: GraphStore) -> Partition
     therefore truncation flags and scan metrics — match the source store.
     """
     e_len, csr_len = int(store.e_len), int(store.csr_len)
-    esrc = np.asarray(store.esrc)
-    edst = np.asarray(store.edst)
-    elabel = np.asarray(store.elabel)
-    ealive = np.asarray(store.ealive)
-    eprops = np.asarray(store.eprops)
-    out = _build_block(pspec, esrc, edst, elabel, ealive, eprops, e_len, csr_len)
-    inc = _build_block(pspec, edst, esrc, elabel, ealive, eprops, e_len, csr_len)
+    h = {f: np.asarray(getattr(store, f)) for f in (
+        "esrc", "edst", "elabel", "ealive", "eprops", "out_perm", "in_perm",
+    )}
+    edges = (h["elabel"], h["ealive"], h["eprops"])
+    with ThreadPoolExecutor(max_workers=2) as pool:
+        out = pool.submit(_build_block, pspec, h["esrc"], h["edst"], *edges,
+                          h["out_perm"], e_len, csr_len)
+        inc = pool.submit(_build_block, pspec, h["edst"], h["esrc"], *edges,
+                          h["in_perm"], e_len, csr_len)
+        out, inc = out.result(), inc.result()
     return PartitionedGraphStore(
-        vlabel=store.vlabel, valive=store.valive, vprops=store.vprops,
-        vversion=store.vversion, out=out, inc=inc,
-        v_len=store.v_len, e_len=store.e_len, version=store.version,
+        vlabel=np.asarray(store.vlabel), valive=np.asarray(store.valive),
+        vprops=np.asarray(store.vprops), vversion=np.asarray(store.vversion),
+        out=out, inc=inc, v_len=np.asarray(store.v_len),
+        e_len=np.asarray(store.e_len), version=np.asarray(store.version),
     )
+
+
+def partition_store(pspec: PartitionedStoreSpec, store: GraphStore) -> PartitionedGraphStore:
+    """``partition_store_host`` on the default device."""
+    return jax.tree_util.tree_map(jnp.asarray, partition_store_host(pspec, store))
 
 
 def abstract_partitioned_store(pspec: PartitionedStoreSpec):

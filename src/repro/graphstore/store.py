@@ -14,6 +14,7 @@ so deletes are O(1) scatter writes and never require index maintenance.
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import jax
@@ -93,23 +94,76 @@ def ingest(
     elabels: np.ndarray,
     eprops: np.ndarray,
 ) -> GraphStore:
-    """Bulk-load a graph (host-side, used by data generators) and compact."""
-    store = empty_store(spec)
+    """Bulk-load a graph (used by data generators) and compact: the
+    ``ingest_host`` store on the default device."""
+    return jax.tree_util.tree_map(
+        jnp.asarray, ingest_host(spec, vlabels, vprops, esrc, edst, elabels, eprops)
+    )
+
+
+def _host_csr(keys: np.ndarray, e_len: int, v_cap: int):
+    """numpy twin of ``compact``'s per-orientation index: the stable
+    argsort is unique, so the permutation is byte-identical. It sorts
+    (key, slot) packed into one int64, several times faster than numpy's
+    stable argsort at 10^8 edges."""
+    slot = np.arange(keys.shape[0], dtype=np.int64)
+    okey = np.where(slot < e_len, keys, INT32_MAX)
+    packed = (okey.astype(np.int64) << 32) | slot
+    packed.sort()
+    perm = (packed & 0xFFFFFFFF).astype(np.int32)
+    indptr = np.searchsorted(
+        okey[perm], np.arange(v_cap + 1), side="left"
+    ).astype(np.int32)
+    return indptr, perm
+
+
+def ingest_host(
+    spec: StoreSpec,
+    vlabels: np.ndarray,
+    vprops: np.ndarray,
+    esrc: np.ndarray,
+    edst: np.ndarray,
+    elabels: np.ndarray,
+    eprops: np.ndarray,
+) -> GraphStore:
+    """Bulk-load and compact on the host: a ``GraphStore`` of numpy arrays.
+    Nothing touches a device, so a store larger than one chip's memory can
+    be built and then partitioned straight onto the mesh
+    (``ShardedTxnRuntime.partition_store``)."""
     nv, ne = len(vlabels), len(esrc)
     assert nv <= spec.v_cap and ne <= spec.e_cap
-    store = store._replace(
-        vlabel=store.vlabel.at[:nv].set(jnp.asarray(vlabels, jnp.int32)),
-        valive=store.valive.at[:nv].set(True),
-        vprops=store.vprops.at[:nv].set(jnp.asarray(vprops, jnp.int32)),
-        esrc=store.esrc.at[:ne].set(jnp.asarray(esrc, jnp.int32)),
-        edst=store.edst.at[:ne].set(jnp.asarray(edst, jnp.int32)),
-        elabel=store.elabel.at[:ne].set(jnp.asarray(elabels, jnp.int32)),
-        ealive=store.ealive.at[:ne].set(True),
-        eprops=store.eprops.at[:ne].set(jnp.asarray(eprops, jnp.int32)),
-        v_len=jnp.int32(nv),
-        e_len=jnp.int32(ne),
+    i32 = np.int32
+
+    def filled(shape, fill, dtype, head):
+        a = np.full(shape, fill, dtype)
+        a[: len(head)] = head
+        return a
+
+    vlabel = filled((spec.v_cap,), -1, i32, np.asarray(vlabels, i32))
+    valive = filled((spec.v_cap,), False, bool, np.ones(nv, bool))
+    missing = int(PROP_MISSING)
+    vprop = filled((spec.v_cap, spec.n_vprops), missing, i32,
+                   np.asarray(vprops).astype(i32).reshape(-1, spec.n_vprops))
+    src = filled((spec.e_cap,), INT32_MAX, i32, np.asarray(esrc, i32))
+    dst = filled((spec.e_cap,), -1, i32, np.asarray(edst, i32))
+    with ThreadPoolExecutor(max_workers=2) as pool:  # sorts release the GIL
+        out_csr = pool.submit(_host_csr, src, ne, spec.v_cap)
+        in_csr = pool.submit(_host_csr, dst, ne, spec.v_cap)
+        (out_indptr, out_perm), (in_indptr, in_perm) = (
+            out_csr.result(), in_csr.result()
+        )
+    return GraphStore(
+        vlabel=vlabel, valive=valive, vprops=vprop,
+        vversion=np.zeros((spec.v_cap,), i32),
+        esrc=src, edst=dst,
+        elabel=filled((spec.e_cap,), -1, i32, np.asarray(elabels, i32)),
+        ealive=filled((spec.e_cap,), False, bool, np.ones(ne, bool)),
+        eprops=filled((spec.e_cap, spec.n_eprops), missing, i32,
+                      np.asarray(eprops).astype(i32).reshape(-1, spec.n_eprops)),
+        out_indptr=out_indptr, out_perm=out_perm,
+        in_indptr=in_indptr, in_perm=in_perm,
+        v_len=i32(nv), e_len=i32(ne), csr_len=i32(ne), version=i32(0),
     )
-    return compact(spec, store)
 
 
 def compact(spec: StoreSpec, store: GraphStore) -> GraphStore:

@@ -17,6 +17,11 @@ VMEM (the production variant would DMA each root's CSR window via
 scalar-prefetched indptr, same math). Predicates arrive statically frozen
 (``ref.pred_static``), so each condition unrolls to its exact comparison
 with wildcard lanes read from the per-row bound params.
+
+This variant runs only in interpret mode: its vector-indexed ref reads
+(``indptr_ref[lroot]`` and the window gathers below) are refused by Mosaic
+("Cannot do int indexing on TPU"), so the serving path runs the XLA
+formulation in ``ref`` on every platform (``ops.block_gather``).
 """
 
 from __future__ import annotations

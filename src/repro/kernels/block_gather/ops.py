@@ -1,13 +1,12 @@
 """Public wrappers for block_gather: the raw fused scan+filter and the
 drop-in owner-local miss executor for the sharded serve tier.
 
-``block_gather`` pads the batch to whole kernel blocks and dispatches to
-the Pallas kernel (compiled on TPU, interpreter elsewhere) or to the
-pure-jnp reference. ``use_pallas=None`` resolves at trace time like
-``cache.CacheSpec.use_pallas``: the Pallas kernel on TPU, the fully
-vectorized reference on CPU/GPU — both are pinned bit-identical by the
-tier-1 parity tests, so the choice is a performance knob, not a semantic
-one.
+``block_gather`` runs the fully vectorized XLA formulation
+(``ref.block_gather_filter_ref``) on every platform, so the chip runs the
+same program the tier-1 tests pin. ``use_pallas=True`` dispatches to the
+Pallas kernel instead (padding the batch to whole kernel blocks), which is
+kept for its interpret-mode parity tests: it loads whole blocks with
+vector-indexed ref reads, which Mosaic refuses to compile for a TPU.
 
 ``block_onehop_exec`` is the fused replacement for
 ``runtime.onehop_exec_view`` over a ``partition.BlockStoreView``: same
@@ -42,13 +41,12 @@ def block_gather(
     csr_len, blk_len, roots, lroot, rvalid, cvalid, rmask, r_ok,
     pe_bound, pl_bound,
     *, max_deg, recent_cap, e_blk_cap, edge_label, pe, pl,
-    block_b=128, use_pallas=None, interpret=None,
+    block_b=128, use_pallas=False, interpret=None,
 ):
     """One orientation's fused scan + filter (see ``ref`` for the operand
-    and output contract). Handles arbitrary batch sizes by padding B up to
-    whole kernel blocks (padded rows are invalid and fully masked)."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    and output contract). The Pallas path handles arbitrary batch sizes by
+    padding B up to whole kernel blocks (padded rows are invalid and fully
+    masked)."""
     statics = dict(
         max_deg=max_deg, recent_cap=recent_cap, e_blk_cap=e_blk_cap,
         edge_label=edge_label, pe=pe, pl=pl,
@@ -108,7 +106,7 @@ def first_occurrence_mask(vals, mask):
 
 def block_onehop_exec(
     espec, view, direction: int, edge_label: int, pr, pe, pl,
-    roots, params, rmask, *, use_pallas=None,
+    roots, params, rmask, *, use_pallas=False,
 ):
     """Fused owner-local miss executor over a ``BlockStoreView`` — the
     partitioned tier's ``exec_fn`` hook. Same contract as

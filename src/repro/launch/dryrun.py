@@ -1,20 +1,24 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
-).strip()
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, record memory/cost analysis and collective bytes.
 
-MUST be run as its own process (the XLA flag above is set before any jax
-import). Results accumulate under experiments/dryrun/ as one JSON per cell
-so partial progress survives crashes.
+Run it as its own process: run as a program, it asks XLA for 512 virtual
+CPU devices before JAX is imported; importing it sets nothing. Results
+accumulate under experiments/dryrun/ as one JSON per cell so partial
+progress survives crashes.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all
   PYTHONPATH=src python -m repro.launch.dryrun --arch glm4-9b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --arch gat-cora --mesh multipod
 """
+
+import os
+
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512"
+    ).strip()
 
 import argparse
 import json

@@ -29,10 +29,13 @@ The loop exercises the full serving life-cycle on a host:
   once they are ready — the growth pause is one device pad, not a
   recompile.
 
-On a real fleet the same ``ShardedTxnRuntime.serve_step`` compiles on the
-production mesh (``graph_serve.config_cell`` / launch/dryrun.py prove it);
-this driver exists so the serving path can be *executed* and validated
-end-to-end on a host.
+The deployment is the paper's eCommerce graph at ``configs.ecommerce_graph``
+``CHIP`` widths and scale per device (``deployment_config``), generated
+in bulk from ``--seed`` (``generate_graph``) and built on the host, so the
+same code loads a chip to its real size; ``--vertices`` scales it down for
+a CPU run. The mesh is ``jax.devices()``' first ``--shards`` devices: for
+virtual CPU devices set
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before starting.
 """
 
 from __future__ import annotations
@@ -43,15 +46,69 @@ import time
 
 import numpy as np
 
+# edge-block fill at ingest: below MaintenancePolicy's 0.85 grow high-water,
+# so a fresh deployment does not grow on its first commit
+BLOCK_FILL = 0.8
+
+
+def deployment_config(n_shards: int = 1, vertices: int | None = None):
+    """The eCommerce deployment for ``n_shards`` devices: ``CHIP``'s
+    per-device scale times the device count, at FULL widths. ``vertices``
+    (a power of two) cuts the scale further for CPU runs, keeping CHIP's
+    ratio of vertices to cache slots."""
+    import dataclasses
+
+    from repro.configs.ecommerce_graph import CHIP
+
+    v_total = CHIP.v_total * n_shards if vertices is None else int(vertices)
+    slots = max(CHIP.cache_slots_total * v_total // CHIP.v_total, 8 * n_shards)
+    return dataclasses.replace(CHIP, v_total=v_total, cache_slots_total=slots)
+
+
+def generate_graph(cfg, seed: int):
+    """A random graph at ``cfg``'s scale and widths, made in bulk from
+    ``seed``: ``e_per_vertex`` out-edges per vertex on average (uniform
+    sources, so degrees are Poisson and stay far below ``max_deg``),
+    uniform destinations, 0/1 properties. One ``recent_cap`` of the edge
+    capacity stays free for appends. Returns the ``ingest`` arguments
+    after the spec: ``(vlabels, vprops, esrc, edst, elabels, eprops)``."""
+    rng = np.random.default_rng(seed)
+    V = cfg.v_total
+    E = cfg.e_total() - cfg.recent_cap
+    deg = np.bincount(rng.integers(0, V, E, dtype=np.int32), minlength=V)
+    esrc = np.repeat(np.arange(V, dtype=np.int32), deg)  # sorted by src
+    edst = rng.integers(0, V, E, dtype=np.int32)
+    eprops = rng.integers(0, 2, (E, cfg.n_eprops), dtype=np.int32)
+    vprops = rng.integers(0, 2, (V, cfg.n_vprops), dtype=np.int32)
+    return (np.zeros(V, np.int32), vprops, esrc, edst,
+            np.zeros(E, np.int32), eprops)
+
+
+def block_capacity(store, n_shards: int) -> int:
+    """Per-shard edge-block capacity for a host store: the fullest
+    orientation's largest owner share at ``BLOCK_FILL``."""
+    from repro.distributed.routing import base_owner
+
+    e_len = int(store.e_len)
+    need = max(
+        int(np.bincount(base_owner(store.esrc[:e_len], n_shards),
+                        minlength=n_shards).max()),
+        int(np.bincount(base_owner(store.edst[:e_len], n_shards),
+                        minlength=n_shards).max()),
+    )
+    return int(np.ceil(need / BLOCK_FILL))
+
 
 def main(argv=None):
     import os
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shards", type=int, default=4)
+    ap.add_argument("--shards", type=int, default=1)
     ap.add_argument("--batches", type=int, default=10)
     ap.add_argument("--batch", type=int, default=64)
-    ap.add_argument("--vertices", type=int, default=1024)
+    ap.add_argument("--vertices", type=int, default=None,
+                    help="total vertices, a power of two (default: the "
+                         "CHIP deployment's 2^23 per shard)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--store-tier", default="partitioned",
                     choices=("partitioned", "replicated"))
@@ -105,11 +162,9 @@ def main(argv=None):
                          "report is always emitted)")
     args = ap.parse_args(argv)
 
-    if args.shards > 1:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.shards}"
-        ).strip()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
 
     from repro.distributed.fault import (
@@ -117,7 +172,7 @@ def main(argv=None):
     )
     from repro.distributed.failover import FailoverController
     from repro.distributed.graph_serve import (
-        GraphServeConfig, ShardedMissDrain, ShardedTxnRuntime, config_espec,
+        ShardedMissDrain, ShardedTxnRuntime, config_espec,
         config_plan_and_ttable,
     )
     from repro.distributed.sharding import flat_mesh
@@ -126,30 +181,16 @@ def main(argv=None):
     )
     from repro.distributed.routing import RoutingTableHost
     from repro.graphstore.migration import HotSetTracker, MigrationEngine
-    from repro.graphstore.store import ingest
+    from repro.graphstore.store import ingest_host
     from repro.obs.metrics import OWNER_STAGE_FIELDS
     from repro.obs.telemetry import ServeTelemetry
 
-    cfg = GraphServeConfig(
-        name="serve-local", v_total=args.vertices, e_per_vertex=4,
-        max_deg=16, max_leaves=16, cache_slots_total=4096, recent_cap=64,
-    )
+    cfg = deployment_config(args.shards, args.vertices)
     espec = config_espec(cfg)
     plan, ttable = config_plan_and_ttable(cfg)
     rng = np.random.default_rng(args.seed)
     V = cfg.v_total
-    # random graph matching the capacity profile
-    es, ed, ep = [], [], []
-    for v in range(V):
-        for _ in range(int(rng.integers(0, cfg.max_deg // 2))):
-            es.append(v)
-            ed.append(int(rng.integers(0, V)))
-            ep.append([int(rng.integers(0, 2))])
-    vlabels = np.zeros(V, np.int32)
-    vprops = rng.integers(0, 2, (V, cfg.n_vprops)).astype(np.int64)
-    store = ingest(
-        espec.store, vlabels, vprops, es, ed, [0] * len(es), np.array(ep)
-    )
+    store = ingest_host(espec.store, *generate_graph(cfg, args.seed))
 
     # telemetry: per-owner stage attribution rides the runtime's existing
     # stacked all-reduce; the tracer times the host-side phases. JSONL
@@ -157,9 +198,11 @@ def main(argv=None):
     # report are always on.
     telemetry = ServeTelemetry(args.shards, trace_path=args.trace)
     mesh = flat_mesh(args.shards)
-    rt = ShardedTxnRuntime(espec, mesh, store_tier=args.store_tier,
-                           tracer=telemetry.tracer)
     partitioned = args.store_tier == "partitioned"
+    rt = ShardedTxnRuntime(
+        espec, mesh, store_tier=args.store_tier, tracer=telemetry.tracer,
+        e_blk_cap=block_capacity(store, args.shards) if partitioned else None,
+    )
     if partitioned:
         sstate = rt.partition_store(store, elastic=True)
         rep = rt.store_bytes()
@@ -170,7 +213,7 @@ def main(argv=None):
             f"ideal 1/n = {rep['ideal_ratio']:.3f})"
         )
     else:
-        sstate = store
+        sstate = jax.device_put(store, rt.store_sharding())
     cache = rt.empty_cache()
     tpl_meta = {0: (plan.hops[0].direction, plan.hops[0].edge_label)}
     # per-owner CP queues: each shard's miss records drain at that shard
@@ -225,9 +268,10 @@ def main(argv=None):
         )
         print("routing: table attached (epoch 0), migration policy loop on")
     if args.hot_frac > 0:
-        # hot roots all land on one owner under the modulo layout
-        hot = np.array([v for v in range(V) if v % args.shards == 1][:16],
-                       np.int64)
+        # hot roots all land on one owner (shard 1, or 0 alone) under the
+        # modulo layout
+        hot_owner = min(1, args.shards - 1)
+        hot = np.arange(hot_owner, V, args.shards, dtype=np.int64)[:16]
     FR = OWNER_STAGE_FIELDS.index("frontier_rows")
 
     total = dict(requests=0, hits=0, misses=0, route_overflow=0, deferred=0,
