@@ -1,0 +1,6 @@
+"""``edges_scanned_per_gr`` in the cells that report ``gr_p99_ms``: the same reader."""
+
+import runpy
+from pathlib import Path
+
+read = runpy.run_path(str(Path(__file__).with_name("edges_scanned_per_gr.py")))["read"]
