@@ -15,6 +15,7 @@ paper's separation of read and write paths.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from typing import NamedTuple
 
@@ -30,50 +31,85 @@ from repro.core.runtime import onehop_exec_view
 from repro.core.templates import TemplateTable, PredSpec
 from repro.graphstore.store import GlobalStoreView, GraphStore
 from repro.graphstore.txn import conflicts
+from repro.obs.trace import NULL_TRACER
 from repro.utils import take_along0
 
 
 class MissQueue:
-    """Host-side FIFO of cache misses with retry accounting."""
+    """Host-side FIFO of cache misses with retry accounting.
+
+    Each record carries its push time (``perf_counter``), kept across
+    retries, so the populator can report how long a miss waited before
+    its entry went in. Counts: ``pushed`` (records accepted), ``deduped``
+    (refused: the same key is already in flight), ``dropped`` (the oldest
+    record evicted by a full queue), ``retried`` and ``discarded`` (OCC
+    aborts requeued and given up), and ``max_depth``, the deepest the
+    queue has been. A dropped or discarded record releases its key, so
+    the same miss can be pushed and populated again.
+    """
 
     def __init__(self, max_retries: int = 3, maxlen: int = 100_000):
-        self.q: deque = deque(maxlen=maxlen)
+        self.q: deque = deque()
+        self.maxlen = maxlen
         self.max_retries = max_retries
-        self.discarded = 0
+        self.pushed = 0
+        self.deduped = 0
+        self.dropped = 0
         self.retried = 0
+        self.discarded = 0
+        self.max_depth = 0
         self._seen_inflight: set = set()
 
     def push(self, records):
         for r in records:
-            key = (r.tpl_idx, r.root, tuple(np.asarray(r.params).tolist()))
+            key = _key(r)
             if key in self._seen_inflight:
-                continue  # dedupe identical in-flight misses
+                self.deduped += 1  # identical miss already in flight
+                continue
             self._seen_inflight.add(key)
-            self.q.append((r, 0))
+            self.pushed += 1
+            self._append((r, 0, time.perf_counter()))
+
+    def _append(self, item):
+        if len(self.q) >= self.maxlen:
+            old = self.q.popleft()[0]
+            self.dropped += 1
+            self._release(old)
+        self.q.append(item)
+        self.max_depth = max(self.max_depth, len(self.q))
 
     def drain(self, k: int):
+        """Pop up to ``k`` ``(record, attempts, pushed_at)`` items."""
         out = []
         while self.q and len(out) < k:
             out.append(self.q.popleft())
         return out
 
-    def requeue(self, rec, attempts):
+    def requeue(self, rec, attempts, pushed_at):
         if attempts + 1 >= self.max_retries:
             self.discarded += 1
             self._release(rec)
         else:
             self.retried += 1
-            self.q.append((rec, attempts + 1))
+            self._append((rec, attempts + 1, pushed_at))
 
     def done(self, rec):
         self._release(rec)
 
     def _release(self, rec):
-        key = (rec.tpl_idx, rec.root, tuple(np.asarray(rec.params).tolist()))
-        self._seen_inflight.discard(key)
+        self._seen_inflight.discard(_key(rec))
+
+    def counts(self) -> dict:
+        return {"pushed": self.pushed, "deduped": self.deduped,
+                "dropped": self.dropped, "retried": self.retried,
+                "discarded": self.discarded, "max_depth": self.max_depth}
 
     def __len__(self):
         return len(self.q)
+
+
+def _key(rec):
+    return (rec.tpl_idx, rec.root, tuple(np.asarray(rec.params).tolist()))
 
 
 def _tpl_row(stacked: PredSpec, t: int) -> PredSpec:
@@ -120,6 +156,10 @@ def populate_step(
     reconstructs the bundle at the inserting shard. With both defaulted
     (single host, or exec == commit shard) nothing is reduced and the
     path is byte-identical to the fused transaction.
+
+    The stages run under the named scopes ``cp/exec`` (the miss
+    re-execution), ``cp/occ`` (the conflict check) and ``cp/insert``, so a
+    profiler trace gives each its device time.
     """
     pr = _tpl_row(ttable.pr, tpl_idx)
     pe = _tpl_row(ttable.pe, tpl_idx)
@@ -127,46 +167,53 @@ def populate_step(
     view = exec_view if exec_view is not None else GlobalStoreView(
         espec.store, store_exec
     )
-    leaves, lmask, n_true, trunc, stats = onehop_exec_view(
-        espec, view, direction, edge_label, pr, pe, pl, roots, params, mask
-    )
-    cacheable = mask & ~trunc & (n_true <= espec.result_width)
+    with jax.named_scope("cp/exec"):
+        leaves, lmask, n_true, trunc, stats = onehop_exec_view(
+            espec, view, direction, edge_label, pr, pe, pl, roots, params, mask
+        )
+        cacheable = mask & ~trunc & (n_true <= espec.result_width)
     cp_read_version = store_exec.version
 
-    # OCC conflict check per entry: the root plus every vertex the execution
-    # observed (scanned neighbors, not just qualifying leaves — a write to a
-    # filtered-out neighbor can change the result as well)
-    read_set = jnp.concatenate([roots[:, None], stats["scanned"]], axis=1)
-    read_mask = jnp.concatenate([mask[:, None], stats["scanned_mask"]], axis=1)
-    conflict = conflicts(
-        espec.store, store_commit, cp_read_version, read_set, read_mask, axis=1
-    )
-    # the write itself must also be enabled for this template (lifecycle) —
-    # reads may only be served for enabled templates, but populating while
-    # installed-for-writes is safe and matches §4.1 Phase 2.
-    ok = cacheable & ~conflict & ttable.read_enabled[tpl_idx]
+    with jax.named_scope("cp/occ"):
+        # OCC conflict check per entry: the root plus every vertex the
+        # execution observed (scanned neighbors, not just qualifying leaves
+        # — a write to a filtered-out neighbor can change the result too)
+        read_set = jnp.concatenate([roots[:, None], stats["scanned"]], axis=1)
+        read_mask = jnp.concatenate(
+            [mask[:, None], stats["scanned_mask"]], axis=1
+        )
+        conflict = conflicts(
+            espec.store, store_commit, cp_read_version, read_set, read_mask,
+            axis=1,
+        )
+        # the write itself must also be enabled for this template
+        # (lifecycle) — reads may only be served for enabled templates, but
+        # populating while installed-for-writes is safe and matches §4.1
+        # Phase 2.
+        ok = cacheable & ~conflict & ttable.read_enabled[tpl_idx]
 
-    insert_ok = ok
-    if commit_mask is not None:
-        assert allreduce is not None, "the CP split needs a reducer"
-        # ship the executed bundle to the inserting shard: one owner per
-        # row contributes, everyone else adds zeros
-        leaves = allreduce(jnp.where(ok[:, None], leaves, 0))
-        n_true = allreduce(jnp.where(ok, n_true, 0))
-        ok_g = allreduce(ok.astype(jnp.int32)) > 0
-        insert_ok = ok_g & commit_mask
+    with jax.named_scope("cp/insert"):
+        insert_ok = ok
+        if commit_mask is not None:
+            assert allreduce is not None, "the CP split needs a reducer"
+            # ship the executed bundle to the inserting shard: one owner
+            # per row contributes, everyone else adds zeros
+            leaves = allreduce(jnp.where(ok[:, None], leaves, 0))
+            n_true = allreduce(jnp.where(ok, n_true, 0))
+            ok_g = allreduce(ok.astype(jnp.int32)) > 0
+            insert_ok = ok_g & commit_mask
 
-    cache = cache_insert(
-        espec.cache,
-        cache,
-        jnp.full(roots.shape, tpl_idx, jnp.int32),
-        roots,
-        params,
-        leaves,
-        n_true,
-        jnp.full(roots.shape, cp_read_version, jnp.int32),
-        insert_ok,
-    )
+        cache = cache_insert(
+            espec.cache,
+            cache,
+            jnp.full(roots.shape, tpl_idx, jnp.int32),
+            roots,
+            params,
+            leaves,
+            n_true,
+            jnp.full(roots.shape, cp_read_version, jnp.int32),
+            insert_ok,
+        )
     return cache, ok, cacheable & conflict
 
 
@@ -210,7 +257,8 @@ class CachePopulator:
                 )
         return self._jitted[key]
 
-    def drain(self, store_exec, store_commit, cache, ttable, k: int = 128):
+    def drain(self, store_exec, store_commit, cache, ttable, k: int = 128,
+              tracer=NULL_TRACER):
         """Process up to k queued misses. Returns the new cache.
 
         CP batches are packed with vectorized numpy slicing (no per-row
@@ -218,20 +266,55 @@ class CachePopulator:
         holds each in-flight (tpl, root, params) key exactly once until it
         is done or discarded, and duplicate keys *within* one jitted insert
         are resolved last-writer-wins by the vectorized ``cache_insert``.
+
+        ``tracer`` times the host phases (``cp_pack``: queue pop, packing
+        and the host-to-device copies; ``cp_dispatch``; ``cp_sync``: the
+        blocking read of the verdicts; ``cp_settle``: requeue and release)
+        and records each committed insert's ``cp_wait``, from the miss's
+        push to the commit's verdict.
         """
-        batch = self.queue.drain(k)
-        if not batch:
-            return cache
+        with tracer.span("cp_pack"):
+            chunks = list(self._chunks(self.queue.drain(k)))
+        for t, bucket, chunk, args in chunks:
+            with tracer.span("cp_dispatch"):
+                cache, ok, conflicted = self._fn(t, bucket)(
+                    store_exec=store_exec,
+                    store_commit=store_commit,
+                    cache=cache,
+                    ttable=ttable,
+                    **args,
+                )
+            with tracer.span("cp_sync"):
+                ok = np.asarray(ok)
+                conflicted = np.asarray(conflicted)
+            with tracer.span("cp_settle"):
+                now = time.perf_counter()
+                for j, (rec, attempts, pushed_at) in enumerate(chunk):
+                    if conflicted[j]:
+                        self.aborted += 1
+                        self.queue.requeue(rec, attempts, pushed_at)
+                    else:
+                        if ok[j]:
+                            self.committed += 1
+                            tracer.record("cp_wait", now - pushed_at)
+                        self.queue.done(rec)
+        return cache
+
+    def _chunks(self, batch):
+        """Group popped queue items by template and pack each bucket-sized
+        chunk: yields ``(tpl_idx, bucket, items, device arguments)``."""
         by_tpl: dict = {}
-        for rec, attempts in batch:
-            by_tpl.setdefault(rec.tpl_idx, []).append((rec, attempts))
+        for item in batch:
+            by_tpl.setdefault(item[0].tpl_idx, []).append(item)
         for t, items in by_tpl.items():
             n = len(items)
-            roots_all = np.fromiter((rec.root for rec, _ in items), np.int32, n)
+            roots_all = np.fromiter((rec.root for rec, _, _ in items), np.int32, n)
             params_all = np.stack(
-                [np.asarray(rec.params, np.int32) for rec, _ in items]
+                [np.asarray(rec.params, np.int32) for rec, _, _ in items]
             ).reshape(n, PARAM_LEN)
-            vers_all = np.fromiter((rec.read_version for rec, _ in items), np.int32, n)
+            vers_all = np.fromiter(
+                (rec.read_version for rec, _, _ in items), np.int32, n
+            )
             bucket = runtime.bucket_for(n, self._BUCKETS, clamp=True)
             for lo in range(0, n, bucket):
                 chunk = items[lo : lo + bucket]
@@ -244,24 +327,9 @@ class CachePopulator:
                 params[:nb] = params_all[lo : lo + nb]
                 vers[:nb] = vers_all[lo : lo + nb]
                 m[:nb] = True
-                fn = self._fn(t, bucket)
-                cache, ok, conflicted = fn(
-                    store_exec=store_exec,
-                    store_commit=store_commit,
-                    cache=cache,
-                    ttable=ttable,
+                yield t, bucket, chunk, dict(
                     roots=jnp.asarray(roots),
                     params=jnp.asarray(params),
                     mask=jnp.asarray(m),
                     read_versions=jnp.asarray(vers),
                 )
-                ok = np.asarray(ok)
-                conflicted = np.asarray(conflicted)
-                for j, (rec, attempts) in enumerate(chunk):
-                    if conflicted[j]:
-                        self.aborted += 1
-                        self.queue.requeue(rec, attempts)
-                    else:
-                        self.committed += int(ok[j])
-                        self.queue.done(rec)
-        return cache

@@ -309,7 +309,8 @@ class MissRecord(NamedTuple):
 
 
 # ----------------------------------------------------------- fused pipeline
-def make_hop_kernel(espec, hop, use_cache: bool, exec_fn=None, defer_fn=None):
+def make_hop_kernel(espec, hop, use_cache: bool, exec_fn=None, defer_fn=None,
+                    name: str = "hop"):
     """One hop of the fused pipeline over a flat root frontier.
 
     Returns ``kernel(store, cache, ttable, roots_flat, rmask_flat,
@@ -343,6 +344,11 @@ def make_hop_kernel(espec, hop, use_cache: bool, exec_fn=None, defer_fn=None):
     the hook absent (single host) or the mask all-False (healthy mesh,
     no locality splits) the program is byte-identical to the
     non-degraded trace — degrading is an *input* change, not a recompile.
+
+    The ``lax.cond`` and both its branches run under the named scope
+    ``<name>/miss_exec``, so a profiler trace gives the miss path's device
+    time by name (the hit select runs only in the miss branch and counts
+    there).
     """
     RW = espec.result_width
     cacheable = hop.tpl_idx >= 0 and use_cache
@@ -415,9 +421,10 @@ def make_hop_kernel(espec, hop, use_cache: bool, exec_fn=None, defer_fn=None):
                     jnp.int32(0), jnp.int32(0),
                     jnp.int32(0), jnp.int32(0))
 
-        vals, cnt, mr, nrec, trunc_n, es, lf = jax.lax.cond(
-            k > 0, run_exec, skip_exec, (roots_flat, miss_mask)
-        )
+        with jax.named_scope(f"{name}/miss_exec"):
+            vals, cnt, mr, nrec, trunc_n, es, lf = jax.lax.cond(
+                k > 0, run_exec, skip_exec, (roots_flat, miss_mask)
+            )
         # deferred rows ride the count channel home as -1 (their cnt is 0
         # on both cond branches, so the encoding is unambiguous)
         cnt = jnp.where(deferred, jnp.int32(-1), cnt)
@@ -487,6 +494,17 @@ class LocalPlanTier:
         return m
 
 
+def _hop_scope(stage: str):
+    """Run a hop-driver stage ``fn(s, hop_idx)`` under the named scope
+    ``hop<hop_idx>/<stage>``."""
+    def wrap(fn):
+        def run(s, hop_idx):
+            with jax.named_scope(f"hop{hop_idx}/{stage}"):
+                return fn(s, hop_idx)
+        return run
+    return wrap
+
+
 def make_plan_fn(espec, plan, use_cache: bool, tier, *, overlap: bool = False):
     """The ROADMAP's shared hop driver: the whole-plan device program —
     every hop's probe + masked miss-exec + frontier merge, the final clause,
@@ -524,13 +542,22 @@ def make_plan_fn(espec, plan, use_cache: bool, tier, *, overlap: bool = False):
     The caller must guarantee an even per-shard batch (and size route caps
     for the half-batch); results are row-identical to the unoverlapped
     schedule when route caps don't drop (e.g. ``route_cap_factor=None``).
+
+    Each stage runs under a named scope, so a profiler trace gives its
+    device time by a name that survives edits: ``hop<h>/route``,
+    ``hop<h>/probe`` (the cache probe and the hop's metrics),
+    ``hop<h>/miss_exec`` (the miss path's ``lax.cond``), ``hop<h>/unroute``,
+    ``hop<h>/merge`` (the frontier merge), then ``finalize`` (post filter
+    and final clause) and ``reduce_metrics``. Scopes are metadata: the
+    compiled program is the same without them.
     """
     F, RW = espec.frontier, espec.result_width
     kernels = [
         make_hop_kernel(
-            espec, hop, use_cache, tier.exec_fn(hop), tier.defer_fn()
+            espec, hop, use_cache, tier.exec_fn(hop), tier.defer_fn(),
+            name=f"hop{h}",
         )
-        for hop in plan.hops
+        for h, hop in enumerate(plan.hops)
     ]
     n_extra = getattr(tier, "extra_inputs", 0)
 
@@ -585,6 +612,7 @@ def make_plan_fn(espec, plan, use_cache: bool, tier, *, overlap: bool = False):
             for i in range(n_streams)
         ]
 
+        @_hop_scope("route")
         def stage_route(s, hop_idx):
             # ---- one packed exchange: frontier roots + flags + bound
             # params travel to their owner shards in a single frame
@@ -600,6 +628,7 @@ def make_plan_fn(espec, plan, use_cache: bool, tier, *, overlap: bool = False):
                 m["route_overflow"] = m["route_overflow"] + ovf
             s["q"], s["qmask"], s["qparams"], s["ctx"] = q, qmask, qparams, ctx
 
+        @_hop_scope("probe")
         def stage_exec(s, hop_idx):
             # ---- owner-local probe + cond-gated miss execution ----
             hop, kernel = plan.hops[hop_idx], kernels[hop_idx]
@@ -630,15 +659,17 @@ def make_plan_fn(espec, plan, use_cache: bool, tier, *, overlap: bool = False):
             # ---- one packed exchange home, then the home-shard on-device
             # dedup/compact merge (cost tracks occupancy) ----
             A, Br = s["A"], s["frontier"].shape[0]
-            vals, cnt = tier.unroute(s["ctx"], s["vals"], s["cnt"])
-            cnt = cnt.reshape(Br, A)
-            # decode the deferred channel: any owner-down slot (cnt = -1)
-            # marks the whole query row bounded-stale
-            s["row_def"] = s["row_def"] | jnp.any(cnt < 0, axis=1)
-            cnt = jnp.maximum(cnt, 0)
-            s["frontier"], s["fmask"] = segmented_dedup_merge(
-                vals.reshape(Br, A, RW), cnt, F
-            )
+            with jax.named_scope(f"hop{hop_idx}/unroute"):
+                vals, cnt = tier.unroute(s["ctx"], s["vals"], s["cnt"])
+            with jax.named_scope(f"hop{hop_idx}/merge"):
+                cnt = cnt.reshape(Br, A)
+                # decode the deferred channel: any owner-down slot
+                # (cnt = -1) marks the whole query row bounded-stale
+                s["row_def"] = s["row_def"] | jnp.any(cnt < 0, axis=1)
+                cnt = jnp.maximum(cnt, 0)
+                s["frontier"], s["fmask"] = segmented_dedup_merge(
+                    vals.reshape(Br, A, RW), cnt, F
+                )
             s["A"] = min(F, A * RW)
 
         if n_streams == 1:
@@ -662,39 +693,42 @@ def make_plan_fn(espec, plan, use_cache: bool, tier, *, overlap: bool = False):
                 stage_exec(sb, h)        # b's exec vs a's hop-(h+1) exchange
                 stage_finish(sb, h)
 
-        for hop in plan.hops:
-            if hop.tpl_idx >= 0 and use_cache:
-                m["phases"] = m["phases"] + 1  # one cache get round-trip
+        with jax.named_scope("finalize"):
+            for hop in plan.hops:
+                if hop.tpl_idx >= 0 and use_cache:
+                    m["phases"] = m["phases"] + 1  # one cache get round-trip
 
-        row_def = jnp.concatenate([s["row_def"] for s in streams])
-        frontier = jnp.concatenate([s["frontier"] for s in streams])
-        fmask = jnp.concatenate([s["fmask"] for s in streams])
-        m["deferred"] = jnp.sum(row_def.astype(jnp.int32))
-        result = finalize_frontier(plan, store, roots, frontier, fmask)
-        if plan.post_filter is not None and plan.post_filter[0] != "id_neq":
-            m["phases"] = m["phases"] + 1  # un-rewritten property fetch
-            m["requests"] = m["requests"] + jnp.sum(fmask.astype(jnp.int32))
-        if plan.final == FINAL_VALUES:
-            m["phases"] = m["phases"] + 1  # valueMap fetch
-            m["requests"] = m["requests"] + jnp.sum(fmask.astype(jnp.int32))
-        m["phases"] = m["phases"] + plan.extra_phases
-        # single deferred reduction: per-hop miss counts ride the metrics
-        # dict through ``reduce_metrics`` (one all-reduce on a mesh), then
-        # gate the per-hop edge-read + leaf-fetch phases on the global count
-        m["_hop_k"] = jnp.stack(hop_k) if H else jnp.zeros((0,), jnp.int32)
-        m = tier.reduce_metrics(m)
-        k_g = m.pop("_hop_k")
-        for h in range(H):
-            m["phases"] = m["phases"] + 2 * (k_g[h] > 0)  # edge read + leaf fetch
-        mr_out = tuple(
-            seg[0] if len(seg) == 1 else jnp.concatenate(seg)
-            for seg in miss_roots if seg
-        )
-        mc_out = tuple(
-            c[0] if len(c) == 1 else
-            jnp.concatenate([jnp.atleast_1d(x) for x in c])
-            for c in miss_counts if c
-        )
+            row_def = jnp.concatenate([s["row_def"] for s in streams])
+            frontier = jnp.concatenate([s["frontier"] for s in streams])
+            fmask = jnp.concatenate([s["fmask"] for s in streams])
+            m["deferred"] = jnp.sum(row_def.astype(jnp.int32))
+            result = finalize_frontier(plan, store, roots, frontier, fmask)
+            if plan.post_filter is not None and plan.post_filter[0] != "id_neq":
+                m["phases"] = m["phases"] + 1  # un-rewritten property fetch
+                m["requests"] = m["requests"] + jnp.sum(fmask.astype(jnp.int32))
+            if plan.final == FINAL_VALUES:
+                m["phases"] = m["phases"] + 1  # valueMap fetch
+                m["requests"] = m["requests"] + jnp.sum(fmask.astype(jnp.int32))
+            m["phases"] = m["phases"] + plan.extra_phases
+        with jax.named_scope("reduce_metrics"):
+            # single deferred reduction: per-hop miss counts ride the
+            # metrics dict through ``reduce_metrics`` (one all-reduce on a
+            # mesh), then gate the per-hop edge-read + leaf-fetch phases on
+            # the global count
+            m["_hop_k"] = jnp.stack(hop_k) if H else jnp.zeros((0,), jnp.int32)
+            m = tier.reduce_metrics(m)
+            k_g = m.pop("_hop_k")
+            for h in range(H):
+                m["phases"] = m["phases"] + 2 * (k_g[h] > 0)  # edge read + leaf fetch
+            mr_out = tuple(
+                seg[0] if len(seg) == 1 else jnp.concatenate(seg)
+                for seg in miss_roots if seg
+            )
+            mc_out = tuple(
+                c[0] if len(c) == 1 else
+                jnp.concatenate([jnp.atleast_1d(x) for x in c])
+                for c in miss_counts if c
+            )
         return (result, row_def, mr_out, mc_out, m, store.version)
 
     return fused

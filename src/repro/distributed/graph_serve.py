@@ -1148,14 +1148,15 @@ class ShardedTxnRuntime:
 
         With ``return_deferred=True`` the per-query deferred flags come
         back as a fourth element."""
+        tr = self.tracer
         B = len(roots)
-        bucket = max(bucket_for(B), self.n)
-        proots, bvalid = pad_roots(roots, bucket)
-        proots, bvalid = jnp.asarray(proots), jnp.asarray(bvalid)
+        with tr.span("gr_prepare"):
+            bucket = max(bucket_for(B), self.n)
+            proots, bvalid = pad_roots(roots, bucket)
+            proots, bvalid = jnp.asarray(proots), jnp.asarray(bvalid)
         rhost = rtable if isinstance(rtable, RoutingTableHost) else (
             self.rhost if rtable is None else None
         )
-        tr = self.tracer
         t0 = time.perf_counter()
         with tr.span("gr_dispatch"):
             out = self._gr(plan, bucket)(
@@ -1331,7 +1332,14 @@ class ShardedTxnRuntime:
         The post-maintenance capacity signals (max block occupancy /
         recent fill, pmax-reduced) and the number of shard-blocks compacted
         come back as step outputs, so the host reads them from the commit's
-        one transfer instead of a follow-up occupancy read."""
+        one transfer instead of a follow-up occupancy read.
+
+        The commit's stages run under named scopes, so a profiler trace
+        gives each its device time: phase A is ``grw/apply`` (the
+        mutations) and ``grw/derive`` (the listener), then ``grw/gate``
+        (the on-device compaction), phase B ``grw/invalidate`` (the routed
+        op stream applied to the cache) and ``grw/signals`` (the reduced
+        stats and capacity signals)."""
         espec = self.espec
         lspec = self.lspec
         pspec = self.pspec if pspec is None else pspec
@@ -1352,14 +1360,16 @@ class ShardedTxnRuntime:
                 # derives ops where the storage lives (ownership masks,
                 # table-aware: a migrated vertex's rows commit and derive
                 # at its table owner)
-                store2, applied, store_ovf = apply_mutations_partitioned(
-                    pspec, store, batch, me, axes, rtable=rtable
-                )
-                ops, sweeps = derive_cache_ops_views(
-                    lspec, BlockStoreView(pspec, store, me, rtable=rtable),
-                    BlockStoreView(pspec, store2, me, rtable=rtable),
-                    ttable, applied, through=through,
-                )
+                with jax.named_scope("grw/apply"):
+                    store2, applied, store_ovf = apply_mutations_partitioned(
+                        pspec, store, batch, me, axes, rtable=rtable
+                    )
+                with jax.named_scope("grw/derive"):
+                    ops, sweeps = derive_cache_ops_views(
+                        lspec, BlockStoreView(pspec, store, me, rtable=rtable),
+                        BlockStoreView(pspec, store2, me, rtable=rtable),
+                        ttable, applied, through=through,
+                    )
                 if gate is not None:
                     # on-device maintenance gate — ops were derived above,
                     # so the layout change cannot perturb this commit's
@@ -1376,13 +1386,14 @@ class ShardedTxnRuntime:
                             lambda b: b,
                             blk,
                         ), hit
-                    out_b, hit_o = maybe_compact(store2.out)
-                    inc_b, hit_i = maybe_compact(store2.inc)
-                    store2 = store2._replace(out=out_b, inc=inc_b)
-                    ncomp = jax.lax.psum(
-                        hit_o.astype(jnp.int32) + hit_i.astype(jnp.int32),
-                        axes,
-                    )
+                    with jax.named_scope("grw/gate"):
+                        out_b, hit_o = maybe_compact(store2.out)
+                        inc_b, hit_i = maybe_compact(store2.inc)
+                        store2 = store2._replace(out=out_b, inc=inc_b)
+                        ncomp = jax.lax.psum(
+                            hit_o.astype(jnp.int32) + hit_i.astype(jnp.int32),
+                            axes,
+                        )
                 else:
                     ncomp = jnp.int32(0)
                 # sweeps gather (local_sweeps=False): the listener derives
@@ -1391,21 +1402,23 @@ class ShardedTxnRuntime:
                 # — every shard applies the full gathered stream, and
                 # non-matching sweeps no-op (byte-identical to the old
                 # owner-local apply when the table is the identity)
-                cache2, occ_delta, ovf = self._route_and_apply_ops(
-                    cache, ops, sweeps, through, local_sweeps=False,
-                    rtable=rtable,
-                )
-                impacted = jax.lax.psum(occ_delta, axes)
-                cache2 = _replicate_stats(cache, cache2, axes)
-                overflow = jax.lax.psum(ovf, axes)
-                # post-maintenance capacity signals, reduced on-device
-                blk_max = jax.lax.pmax(jnp.maximum(
-                    store2.out.blk_len[0], store2.inc.blk_len[0]
-                ), axes)
-                rec_max = jax.lax.pmax(jnp.maximum(
-                    store2.out.blk_len[0] - store2.out.csr_len[0],
-                    store2.inc.blk_len[0] - store2.inc.csr_len[0],
-                ), axes)
+                with jax.named_scope("grw/invalidate"):
+                    cache2, occ_delta, ovf = self._route_and_apply_ops(
+                        cache, ops, sweeps, through, local_sweeps=False,
+                        rtable=rtable,
+                    )
+                    impacted = jax.lax.psum(occ_delta, axes)
+                with jax.named_scope("grw/signals"):
+                    cache2 = _replicate_stats(cache, cache2, axes)
+                    overflow = jax.lax.psum(ovf, axes)
+                    # post-maintenance capacity signals, reduced on-device
+                    blk_max = jax.lax.pmax(jnp.maximum(
+                        store2.out.blk_len[0], store2.inc.blk_len[0]
+                    ), axes)
+                    rec_max = jax.lax.pmax(jnp.maximum(
+                        store2.out.blk_len[0] - store2.out.csr_len[0],
+                        store2.inc.blk_len[0] - store2.inc.csr_len[0],
+                    ), axes)
                 return (store2, cache2, impacted, overflow, store_ovf,
                         blk_max, rec_max, ncomp)
         else:
@@ -1414,21 +1427,25 @@ class ShardedTxnRuntime:
             def local_grw(store, cache, ttable, batch, rtable):
                 me = jax.lax.axis_index(axes)
                 # every replica applies the same commit (deterministic)
-                store2, applied = apply_mutations(espec.store, store, batch)
+                with jax.named_scope("grw/apply"):
+                    store2, applied = apply_mutations(espec.store, store, batch)
                 # phase A: derive impacted keys from this shard's slice
                 # of the mutation batch (round-robin rows)
-                part = shard_mutation_rows(applied, n, me)
-                ops, sweeps = derive_cache_ops(
-                    espec, store, store2, ttable, part, through=through,
-                    row_offset=me, row_stride=n,
-                )
-                cache2, occ_delta, ovf = self._route_and_apply_ops(
-                    cache, ops, sweeps, through, local_sweeps=False,
-                    rtable=rtable,
-                )
-                impacted = jax.lax.psum(occ_delta, axes)
-                cache2 = _replicate_stats(cache, cache2, axes)
-                overflow = jax.lax.psum(ovf, axes)
+                with jax.named_scope("grw/derive"):
+                    part = shard_mutation_rows(applied, n, me)
+                    ops, sweeps = derive_cache_ops(
+                        espec, store, store2, ttable, part, through=through,
+                        row_offset=me, row_stride=n,
+                    )
+                with jax.named_scope("grw/invalidate"):
+                    cache2, occ_delta, ovf = self._route_and_apply_ops(
+                        cache, ops, sweeps, through, local_sweeps=False,
+                        rtable=rtable,
+                    )
+                    impacted = jax.lax.psum(occ_delta, axes)
+                with jax.named_scope("grw/signals"):
+                    cache2 = _replicate_stats(cache, cache2, axes)
+                    overflow = jax.lax.psum(ovf, axes)
                 z = jnp.int32(0)
                 return store2, cache2, impacted, overflow, z, z, z, z
 
@@ -1656,19 +1673,31 @@ class ShardedMissDrain:
 
     def push(self, misses):
         rhost = self.rt.rhost
-        for m in misses:
-            # each miss lands at its root's CACHE owner queue — under the
-            # routing table that is where the insert commits (and, for an
-            # unsplit vertex, where its rows execute)
-            owner = (int(rhost.cache_owner(int(m.root))) if rhost is not None
-                     else int(base_owner(m.root, self.n)))
-            self.pops[owner].queue.push([m])
+        with self.rt.tracer.span("cp_push"):
+            for m in misses:
+                # each miss lands at its root's CACHE owner queue — under
+                # the routing table that is where the insert commits (and,
+                # for an unsplit vertex, where its rows execute)
+                owner = (int(rhost.cache_owner(int(m.root)))
+                         if rhost is not None
+                         else int(base_owner(m.root, self.n)))
+                self.pops[owner].queue.push([m])
 
     def drain(self, store_exec, store_commit, cache, ttable, k: int = 128):
-        """Drain up to ``k`` misses per shard queue; returns the new cache."""
+        """Drain up to ``k`` misses per shard queue; returns the new cache.
+        The runtime's tracer times each populator's host phases."""
         for pop in self.pops:
-            cache = pop.drain(store_exec, store_commit, cache, ttable, k)
+            cache = pop.drain(store_exec, store_commit, cache, ttable, k,
+                              tracer=self.rt.tracer)
         return cache
+
+    def queue_counts(self) -> dict:
+        """``MissQueue.counts`` summed over the shard queues
+        (``max_depth`` is the deepest single queue)."""
+        per = [p.queue.counts() for p in self.pops]
+        out = {k: sum(c[k] for c in per) for k in per[0]}
+        out["max_depth"] = max(c["max_depth"] for c in per)
+        return out
 
     @property
     def committed(self) -> int:

@@ -183,6 +183,7 @@ def main(argv=None):
     from repro.graphstore.migration import HotSetTracker, MigrationEngine
     from repro.graphstore.store import ingest_host
     from repro.obs.metrics import OWNER_STAGE_FIELDS
+    from repro.obs.schema import LATENCY_CLASSES
     from repro.obs.telemetry import ServeTelemetry
 
     cfg = deployment_config(args.shards, args.vertices)
@@ -432,6 +433,8 @@ def main(argv=None):
         f"populated={drain.committed} route_overflow={total['route_overflow']} "
         f"({dt/args.batches*1e3:.1f} ms/batch after compile)"
     )
+    print("cp queue: " + " ".join(
+        f"{k}={v}" for k, v in drain.queue_counts().items()))
     if partitioned:
         occ = rt.store_occupancy(sstate)
         print(
@@ -501,7 +504,7 @@ def main(argv=None):
     def _ms(v):
         return "n/a" if v is None else f"{v * 1e3:.2f}ms"
 
-    for cls in ("gr_cached", "gr_uncached", "grw", "cp_drain"):
+    for cls in LATENCY_CLASSES:
         p = report["latency"][cls]
         print(
             f"latency[{cls}]: p50={_ms(p['p50'])} p95={_ms(p['p95'])} "

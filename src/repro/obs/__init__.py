@@ -9,7 +9,8 @@ from device-side modules without pulling in jax):
 - :mod:`repro.obs.trace` — a low-overhead ``Span``/``Tracer`` API for
   host-side per-phase wall-clock (dispatch, device step, unpack, journal
   flush, checkpoint, compaction tick, hot-swap pause), with optional
-  structured JSONL export.
+  structured JSONL export; a ``Tracer`` also annotates the JAX
+  profiler's trace (it imports ``jax.profiler`` when made).
 - :mod:`repro.obs.metrics` — the per-owner/per-stage device metrics
   block that rides the serving step's existing stacked all-reduce
   (field order contract + host-side attribution helpers, including the

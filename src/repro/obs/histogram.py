@@ -1,8 +1,8 @@
 """Fixed-bucket log-scale latency histograms with exact merge algebra.
 
 The serve loop needs live p50/p95/p99/p99.9 for several traffic classes
-(cached vs. uncached gR-Txs, gRW commits, CP drains) without storing raw
-samples. A log-scale fixed-bucket histogram gives bounded relative error:
+(gR steps, gRW commits, CP drains) without storing raw samples. A
+log-scale fixed-bucket histogram gives bounded relative error:
 with ``buckets_per_decade = 16`` every bucket spans a ratio of
 ``10**(1/16) ~ 1.155``, so any quantile read off the histogram is within
 ~15% (one bucket) of the true sample quantile — far below the

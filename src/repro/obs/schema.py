@@ -22,15 +22,16 @@ import math
 
 from repro.obs.metrics import OWNER_STAGE_FIELDS
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 EVENT_TYPES = ("meta", "span", "snapshot", "report")
 
 # percentile keys every latency-class entry must carry
 PCT_KEYS = ("p50", "p95", "p99", "p999")
 
-# traffic classes the serve loop reports
-LATENCY_CLASSES = ("gr_cached", "gr_uncached", "grw", "cp_drain")
+# traffic classes the serve loop reports: one sample per gR step, gRW
+# commit and CP drain
+LATENCY_CLASSES = ("gr_step", "grw", "cp_drain")
 
 
 def _fail(path: str, msg: str):

@@ -2,17 +2,16 @@
 
 Composes the histogram, trace, and owner-stage pieces into the object
 ``repro.launch.serve`` drives: per-traffic-class streaming latency
-histograms (cached vs. uncached gR-Txs, gRW commits, CP drains),
+histograms (gR steps, gRW commits, CP drains),
 per-owner step-latency histograms, cumulative owner-stage counters, and
 periodic JSONL snapshots plus an end-of-run report (both schema-valid
 per :mod:`repro.obs.schema`).
 
-Cached/uncached gR attribution is weighted at batch granularity: a
-batch whose step took ``t`` seconds with ``h`` probe hits and ``m``
-miss rows contributes ``t`` to the cached-class histogram with weight
-``h`` and to the uncached class with weight ``m`` — the streaming
-analogue of the paper's per-class response-time tables, without
-tracking individual transactions through the fused device step.
+The gR class is the step, recorded once per ``run_gr_tx_batch``: one
+fused device step serves the whole batch, hits and misses alike, so its
+wall-clock is the step's time and no single request's latency (a
+request's latency also holds its queueing, which only the caller that
+scheduled it can see).
 """
 
 from __future__ import annotations
@@ -72,10 +71,7 @@ class ServeTelemetry:
         for k, v in metrics.items():
             if isinstance(v, (int, np.integer)):
                 self.counters[k] = self.counters.get(k, 0) + int(v)
-        hits = int(metrics.get("hits", 0))
-        misses = int(metrics.get("misses", 0))
-        self.latency["gr_cached"].record(step_seconds, weight=max(hits, 0))
-        self.latency["gr_uncached"].record(step_seconds, weight=max(misses, 0))
+        self.latency["gr_step"].record(step_seconds)
         if owner_stage is None:
             return None
         stage = np.asarray(owner_stage, dtype=np.int64)

@@ -3,8 +3,14 @@
 The serve loop wraps each host-side phase (device dispatch, blocking
 sync, result unpack, journal flush, checkpoint, compaction tick,
 hot-swap pause) in ``tracer.span(name)``. A span costs one
-``perf_counter`` pair plus a dict update (~1-2 us) — negligible against
-multi-millisecond serve batches; ``tests/test_obs.py`` pins the bound.
+``perf_counter`` pair, a dict update and a profiler annotation (a few
+us) — negligible against multi-millisecond serve batches;
+``tests/test_obs.py`` pins the bound.
+
+Each ``Tracer`` span also enters a ``jax.profiler.TraceAnnotation`` of
+the same name, so a profiler trace taken while it is attached shows the
+host phases on the same clock as the device ops: an idle gap on the
+device can be read against the span the host was in.
 
 ``NullTracer`` (the module-level ``NULL_TRACER``) is the zero-cost
 default: its ``span`` returns a shared re-entrant no-op context
@@ -61,9 +67,10 @@ class JsonlTraceWriter:
 
 
 class _Span:
-    """Context manager timing one named phase; records into its tracer."""
+    """Context manager timing one named phase; records into its tracer
+    and annotates the profiler's trace with the same name."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "seconds")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "seconds", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict | None):
         self._tracer = tracer
@@ -71,13 +78,16 @@ class _Span:
         self.attrs = attrs
         self._t0 = 0.0
         self.seconds = 0.0
+        self._note = tracer.annotation(name)
 
     def __enter__(self):
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.seconds = time.perf_counter() - self._t0
+        self._note.__exit__(exc_type, exc, tb)
         self._tracer.record(self.name, self.seconds, self.attrs)
         return False
 
@@ -128,6 +138,11 @@ class Tracer:
 
     def __init__(self, sink: JsonlTraceWriter | None = None,
                  emit_spans: bool = True):
+        # imported here, not with the module: the obs tier stays
+        # importable without jax until a tracer is made
+        from jax.profiler import TraceAnnotation
+
+        self.annotation = TraceAnnotation
         self.sink = sink
         self.emit_spans = emit_spans
         self._lock = threading.Lock()
@@ -139,6 +154,8 @@ class Tracer:
 
     def record(self, name: str, seconds: float,
                attrs: dict | None = None) -> None:
+        """Add one sample of ``name`` that lasted ``seconds`` and ended
+        now; its JSONL event's ``ts`` is the wall-clock start."""
         with self._lock:
             st = self._stats.get(name)
             if st is None:
@@ -151,7 +168,7 @@ class Tracer:
             h.record(seconds)
         if self.sink is not None and self.emit_spans:
             ev = {"type": "span", "name": name, "dur_s": seconds,
-                  "ts": time.time()}
+                  "ts": time.time() - seconds}
             if attrs:
                 ev["attrs"] = attrs
             self.sink.emit(ev)

@@ -1,6 +1,8 @@
 """The chip deployment's host side: config scaling, the bulk generator,
 host-side ingest and partitioning, and where the compile cache lives."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -93,8 +95,12 @@ def test_partition_is_host_side_and_fits_block_capacity(n_shards):
 @pytest.fixture
 def cache_dir_config():
     was = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
+    files = jax.config.jax_hlo_source_file_canonicalization_regex
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex", files)
 
 
 def test_compile_cache_defaults_to_the_checkout(monkeypatch, cache_dir_config):
@@ -103,6 +109,11 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch, cache_dir_config):
     assert path == str(compile_cache.CHECKOUT / ".jax_cache")
     assert (compile_cache.CHECKOUT / "src" / "repro").is_dir()
     assert jax.config.jax_compilation_cache_dir == path
+    # stage names are metadata: a renamed stage must not load an old entry,
+    # and the checkout's location must not change the key
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    assert re.match(jax.config.jax_hlo_source_file_canonicalization_regex,
+                    str(compile_cache.CHECKOUT / "src" / "repro" / "x.py"))
 
 
 def test_compile_cache_follows_the_variable(monkeypatch, tmp_path,

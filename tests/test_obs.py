@@ -143,6 +143,7 @@ def test_tracer_accounting_and_jsonl_export(tmp_path):
         for _ in range(3):
             with tr.span("phase_a", shard=1):
                 pass
+        t_b = time.time()
         with tr.span("phase_b"):
             time.sleep(0.002)
     snap = tr.snapshot()
@@ -155,6 +156,31 @@ def test_tracer_accounting_and_jsonl_export(tmp_path):
     for ev in events:
         assert validate_event(ev) == "span"
     assert events[0]["attrs"] == {"shard": 1}
+    # a span event's ts is its wall-clock start, not its end
+    assert events[3]["name"] == "phase_b"
+    assert abs(events[3]["ts"] - t_b) < 1e-3
+
+
+def test_tracer_spans_annotate_the_profiler_trace(tmp_path):
+    """A ``Tracer`` span is also a profiler annotation of the same name,
+    on the trace's clock; ``NULL_TRACER`` annotates nothing."""
+    import jax
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.span("traced_phase"):
+        time.sleep(0.003)
+    with NULL_TRACER.span("null_phase"):
+        time.sleep(0.001)
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    events = [ev for plane in pd.planes for line in plane.lines
+              for ev in line.events]
+    (note,) = [ev for ev in events if ev.name == "traced_phase"]
+    assert note.duration_ns >= 3e6
+    assert note.duration_ns / 1e9 <= tr.snapshot()["traced_phase"]["total_s"] + 1e-3
+    assert not any(ev.name == "null_phase" for ev in events)
 
 
 def test_null_tracer_is_shared_and_stateless():
@@ -251,6 +277,7 @@ def test_serve_telemetry_stream_is_schema_valid(tmp_path):
     assert validate_event(rep, shards=n) == "report"
     assert rep["batches"] == 6
     assert rep["counters"]["hits"] == 18
+    assert rep["latency"]["gr_step"]["count"] == 6  # once per step
     for cls in LATENCY_CLASSES:
         assert rep["latency"][cls]["count"] > 0
     tel.close()
@@ -264,9 +291,10 @@ def test_serve_telemetry_without_device_attribution():
     tel = ServeTelemetry(2)  # no trace path: aggregate-only mode
     assert tel.record_gr(0.01, {"hits": 0, "misses": 4}) is None
     rep = tel.report()
-    assert rep["latency"]["gr_uncached"]["count"] == 4
-    assert rep["latency"]["gr_cached"]["count"] == 0
-    assert rep["latency"]["gr_cached"]["p99"] is None  # empty class -> null
+    # one sample per step, however many rows hit or missed
+    assert rep["latency"]["gr_step"]["count"] == 1
+    assert rep["latency"]["grw"]["count"] == 0
+    assert rep["latency"]["grw"]["p99"] is None  # empty class -> null
     assert validate_event(rep, shards=2) == "report"
 
 
