@@ -74,9 +74,10 @@ def test_gr_step_collective_counts():
         cache = rt.empty_cache()
         for plan in (fig1_plan(), common_watchlist_plan()):
             step = rt.serve_step(plan, 64)
+            # roots and valid flags ride one packed [bucket, 2] input
             hlo = step.jitted.lower(
-                pstore, cache, ttable, jnp.zeros(64, jnp.int32),
-                jnp.ones(64, bool), rt._down_none(), rt._rtable_none(),
+                pstore, cache, ttable, jnp.ones((64, 2), jnp.int32),
+                rt._down_none(), rt._rtable_none(),
             ).compile().as_text()
             c = analyze(hlo)["counts"]
             h = len(plan.hops)
@@ -116,8 +117,8 @@ def test_routing_table_keeps_collective_budget_and_program():
                  rtable=rhost.device_table())
             assert step.jitted._cache_size() == 1, step.jitted._cache_size()
             hlo = step.jitted.lower(
-                pstore, cache, ttable, roots, bv, rt._down_none(),
-                rhost.device_table(),
+                pstore, cache, ttable, jnp.stack([roots, bv], axis=1),
+                rt._down_none(), rhost.device_table(),
             ).compile().as_text()
             c = analyze(hlo)["counts"]
             assert c["all-to-all"] == 2 * h, (h, c)
@@ -190,8 +191,8 @@ def test_telemetry_keeps_collective_budget_and_bytes():
             for rt, ps in ((rt_t, ps_t), (rt_p, ps_p)):
                 step = rt.serve_step(plan, 64)
                 hlo = step.jitted.lower(
-                    ps, rt.empty_cache(), ttable, jnp.zeros(64, jnp.int32),
-                    jnp.ones(64, bool), rt._down_none(), rt._rtable_none(),
+                    ps, rt.empty_cache(), ttable, jnp.ones((64, 2), jnp.int32),
+                    rt._down_none(), rt._rtable_none(),
                 ).compile().as_text()
                 c = analyze(hlo)["counts"]
                 assert c["all-to-all"] == 2 * h, (h, c)
